@@ -143,15 +143,13 @@ def model_flops(cfg, shape) -> float:
 def analyze(compiled, hlo_text: str, cfg, shape, mesh_name: str,
             n_chips: int) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(hlo_text)
     mem = compiled.memory_analysis()
-    temp = getattr(mem, "temp_size_in_bytes", 0)
-    args = getattr(mem, "argument_size_in_bytes", 0)
-    outs = getattr(mem, "output_size_in_bytes", 0)
+    temp = mem.temp_size_in_bytes
+    args = mem.argument_size_in_bytes
+    outs = mem.output_size_in_bytes
     return Roofline(
         arch=cfg.name, shape=shape.name, mesh=mesh_name, n_chips=n_chips,
         hlo_flops=flops, hlo_bytes=byts,

@@ -157,11 +157,10 @@ class KernelSpec:
     the per-kernel row granularities (``block_h`` for ``conv2d_rows``,
     ``bq``/``bk`` for ``swa_attention``, ``chunk`` for ``ssd_chunk``).
 
-    ``interpret`` is tri-state: ``None`` defers to the environment
-    (``REPRO_PALLAS_INTERPRET`` override, else interpret everywhere but a
-    real TPU — see :func:`repro.kernels.ops.default_interpret`), so the
-    same logged plan runs the Pallas interpreter on CPU CI and the
-    compiled lowering on TPU.
+    ``interpret`` is tri-state: ``None`` defers to the platform
+    (interpret everywhere but on a TPU — see
+    :func:`repro.kernels.resolve_interpret`), so the same logged plan runs
+    the Pallas interpreter on CPU CI and the compiled lowering on TPU.
     """
 
     backend: str = "lax"              # "lax" | "pallas"
@@ -169,7 +168,7 @@ class KernelSpec:
     bq: int = 128                     # swa_attention query block
     bk: int = 128                     # swa_attention kv block
     chunk: int = 128                  # ssd_chunk sequence chunk
-    interpret: Optional[bool] = None  # None = env/platform default
+    interpret: Optional[bool] = None  # None = platform default
 
     def __post_init__(self):
         if self.backend not in ("lax", "pallas"):

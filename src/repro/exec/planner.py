@@ -861,8 +861,7 @@ class Planner(_ServePlannerMixin):
         records the search under the ``autotune`` / ``autotune_us``
         extras; when no candidate passes the pricers the plan falls back
         to lax with the usual ``kernel_fallback`` reason."""
-        spec0 = base_spec or plan.kernel or KernelSpec(backend="pallas",
-                                                       interpret=True)
+        spec0 = base_spec or plan.kernel or KernelSpec(backend="pallas")
         spec0 = dataclasses_replace(spec0, backend="pallas")
         target = PALLAS_ALTERNATE.get(plan.engine, plan.engine)
         if target not in PALLAS_ENGINES:

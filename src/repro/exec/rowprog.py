@@ -39,16 +39,14 @@ applied *here*, uniformly, so every row-program engine gains it for free:
   sublinear-memory end of the retain-vs-recompute tradeoff; O(N^2) row
   steps, zero extra residency).
 
-Host offload targets the first host-side memory kind the backend exposes
-(``pinned_host`` on TPU/GPU).  On hosts whose default memory *is* host
-memory (CPU CI) the transfer is a placement no-op but the program
-structure — including the double-buffered fetch schedule — is exercised
-identically, so one logged plan behaves the same everywhere.
+Host offload targets ``pinned_host`` on an accelerator.  On CPU hosts
+the transfer is a placement no-op, decided by the platform, but the
+program structure — including the double-buffered fetch schedule — is
+exercised identically, so one logged plan behaves the same everywhere.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -59,69 +57,46 @@ from jax import lax
 from repro import obs
 from repro.exec.plan import ResidencySpec
 
-try:  # jax >= 0.4.35 keeps this internal; public alias landed later
-    from jax.sharding import TransferToMemoryKind as _TransferToMemoryKind
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax._src.sharding_impls import TransferToMemoryKind \
-        as _TransferToMemoryKind
-
 
 # ---------------------------------------------------------------------------
 # memory-kind helpers
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
+def offload_is_noop() -> bool:
+    """True on CPU hosts, where host and device memory are one space: the
+    policy is still recorded and the transfer schedule still runs, but
+    nothing moves and peak device bytes are unchanged.  Decided by the
+    platform, not by the memories a device lists (a CPU device lists
+    ``pinned_host`` too, yet cannot place a buffer there eagerly)."""
+    return jax.default_backend() == "cpu"
+
+
 def default_memory_kind() -> str:
-    """The backend's accelerator-resident memory kind ("device" on
-    TPU/GPU; host memory on CPU, where they coincide)."""
+    """The backend's accelerator-resident memory kind."""
     return jax.devices()[0].default_memory().kind
 
 
-@functools.lru_cache(maxsize=None)
 def host_memory_kind() -> str:
-    """The memory kind host offload targets: ``pinned_host`` when the
-    backend exposes it, else the first host-side kind, else the default
-    kind (making offload a structural no-op — see module docstring)."""
-    dev = jax.devices()[0]
-    try:
-        kinds = [m.kind for m in dev.addressable_memories()]
-    except Exception:  # backends without memories support
-        return default_memory_kind()
-    for kind in ("pinned_host", "unpinned_host"):
-        if kind in kinds:
-            return kind
-    return default_memory_kind()
+    """The memory kind host offload targets: ``pinned_host`` on an
+    accelerator, the default kind on CPU (see :func:`offload_is_noop`)."""
+    return default_memory_kind() if offload_is_noop() else "pinned_host"
 
 
-def offload_is_noop() -> bool:
-    """True when host offload cannot leave the default memory space (CPU
-    hosts) — policy is still recorded and the transfer schedule still
-    runs, but peak accelerator bytes are unchanged."""
-    return host_memory_kind() == default_memory_kind()
-
-
-@functools.partial(jax.jit, static_argnames=("kind",))
-def _transfer(x, *, kind: str):
-    """Move every leaf of ``x`` to memory ``kind``.  Jitted so the
-    ``TransferToMemoryKind`` form is legal from eager callers too (it
-    inlines as a plain transfer under an outer jit)."""
-    return jax.tree.map(
-        lambda l: jax.device_put(l, _TransferToMemoryKind(kind)), x)
+def _transfer(x, space):
+    if offload_is_noop() or not jax.tree.leaves(x):
+        return x
+    return jax.tree.map(lambda l: jax.device_put(l, space), x)
 
 
 def to_host(x):
-    """Offload a pytree to host memory (identity on no-leaf trees)."""
-    if not jax.tree.leaves(x):
-        return x
-    return _transfer(x, kind=host_memory_kind())
+    """Offload a pytree to pinned host memory (identity on CPU)."""
+    return _transfer(x, jax.memory.Space.Host)
 
 
 def to_device(x):
-    """Fetch a pytree back into accelerator memory."""
-    if not jax.tree.leaves(x):
-        return x
-    return _transfer(x, kind=default_memory_kind())
+    """Fetch a pytree back into accelerator memory (identity on CPU)."""
+    return _transfer(x, jax.memory.Space.Device)
 
 
 # ---------------------------------------------------------------------------

@@ -2,20 +2,20 @@
 
 Interpret-mode policy is plan-carried, not a module constant: engines pass
 ``KernelSpec.interpret`` down explicitly, and standalone callers (tests,
-benchmarks) leave ``interpret=None`` to get the environment default —
-``REPRO_PALLAS_INTERPRET=0|1`` when set, else the Pallas interpreter on
-every backend except a real TPU.  CPU CI and TPU runs therefore share one
-code path; the flag is the only difference.
+benchmarks) leave ``interpret=None`` to get the platform default — the
+Pallas interpreter on every backend except a TPU
+(:func:`repro.kernels.resolve_interpret`).  CPU CI and TPU runs therefore
+share one code path.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 
+from repro.kernels import resolve_interpret
 from repro.kernels.conv2d_rows import conv2d_rows as _conv2d_rows
 from repro.kernels.ssd_chunk import ssd_scan as _ssd
 from repro.kernels.swa_attention import swa_attention as _swa
@@ -66,21 +66,6 @@ def candidate_tiles(kind: str, *, h_out: int = 0, seq: int = 0) -> tuple:
                      if not seq or (c <= seq and seq % c == 0))
     raise ValueError(f"unknown tile kind {kind!r}; "
                      f"known: 'conv', 'swa', 'ssd'")
-
-
-def default_interpret() -> bool:
-    """Environment default for ``pallas_call(interpret=...)``:
-    ``REPRO_PALLAS_INTERPRET`` (0/1) when set, else interpret on anything
-    that is not a TPU."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "")
-    return jax.default_backend() != "tpu"
-
-
-def resolve_interpret(flag: Optional[bool] = None) -> bool:
-    """Tri-state ``KernelSpec.interpret`` -> concrete pallas_call flag."""
-    return default_interpret() if flag is None else bool(flag)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "block_h",

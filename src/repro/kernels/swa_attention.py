@@ -14,11 +14,14 @@ VMEM working set: q block (bq x D) + kv block (bk x D) x 2 + acc (bq x D)
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -76,8 +79,9 @@ def vmem_bytes(bq: int, bk: int, d: int) -> int:
 
 
 def swa_attention(q, k, v, *, window: int, bq: int = 128, bk: int = 128,
-                  interpret: bool = True):
-    """q/k/v: (B, H, S, D) -> (B, H, S, D); causal sliding-window."""
+                  interpret: Optional[bool] = None):
+    """q/k/v: (B, H, S, D) -> (B, H, S, D); causal sliding-window.
+    ``interpret=None`` compiles on a TPU and interprets elsewhere."""
     B, H, S, D = q.shape
     bq = min(bq, S)
     bk = min(bk, S)
@@ -123,6 +127,6 @@ def swa_attention(q, k, v, *, window: int, bq: int = 128, bk: int = 128,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kp, vp)
     return out.reshape(B, H, S, D)

@@ -23,6 +23,7 @@ from repro.analysis.costmodel import analyze as cost_analyze
 from repro.analysis.roofline import analyze
 from repro.configs import get_config, list_configs
 from repro.exec import Planner, ResidencySpec, kernelize_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, production_mesh_spec
 from repro.launch.steps import SHAPES, build_jitted, shape_applicable
 from repro.obs.audit import memory_metrics, plan_audit
@@ -101,8 +102,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, fsdp: bool,
                        "mesh_name": mesh_name})
             if verbose:
                 cost = compiled.cost_analysis()
-                if isinstance(cost, list):  # newer jaxlib: one dict per device
-                    cost = cost[0] if cost else {}
                 print(f"[{arch} x {shape_name} x {mesh_name}] "
                       f"memory_analysis: {mem}")
                 print(f"[{arch} x {shape_name} x {mesh_name}] "
@@ -171,6 +170,7 @@ def main():
     add_plan_cache_arg(ap)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     overrides = _parse_overrides(args.set)
     configure_from_args(args, tool="dryrun", arch=args.arch,
                         shape=args.shape)

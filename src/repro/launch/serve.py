@@ -94,10 +94,12 @@ def main():
     from repro import obs
     from repro.configs import get_config, get_reduced
     from repro.exec import MeshSpec
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.lm import encdec as ED
     from repro.models.lm import model as LM
     from repro.serve import SLO, make_requests, serve
 
+    enable_compile_cache()
     configure_from_args(args, tool="serve", arch=args.arch,
                         cache_kind=args.cache_kind, traffic=args.traffic)
 

@@ -18,8 +18,8 @@ Two modes:
             wrapper, LM via in_shardings from launch.steps.
 * pallas:  add --kernel pallas: the resolved plan is kernelized — its
             engine swapped for the Pallas-backed alternate (rows as VMEM
-            grid steps; interpret mode off-TPU, REPRO_PALLAS_INTERPRET
-            overrides) with automatic lax fallback when the tiling is
+            grid steps; interpret mode off-TPU, compiled on a TPU) with
+            automatic lax fallback when the tiling is
             infeasible.  Composes with --mesh: kernel-backed engines
             inherit their kind's shard wrapper.  Both paths execute the
             swap where the plan's engine runs — the CNN trunk via
@@ -42,6 +42,7 @@ Checkpoints + metrics land in --out.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
 
@@ -54,6 +55,7 @@ from repro.ckpt import store
 from repro.data.pipeline import (
     ImageDataset, ImageDatasetConfig, TokenDataset, TokenDatasetConfig,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.audit import measure_step, plan_audit
 from repro.obs.cli import add_obs_args, configure_from_args, profiled
 from repro.obs.steplog import StepLog
@@ -110,8 +112,8 @@ def _audit_step(step_fn, plan, source_extra, *step_args,
 
 
 def train_lm(args):
-    import dataclasses
-
+    if args.batch is None:
+        args.batch = 8
     from repro.configs import get_config, get_reduced
     from repro.exec import MeshSpec, Planner, ResidencySpec
     from repro.models.lm import model as LM
@@ -238,8 +240,29 @@ def train_lm(args):
     return steplog.records
 
 
-def train_cnn(args):
-    import dataclasses
+@dataclasses.dataclass
+class CNNRun:
+    """A CNN trainer ready to step: the resolved plan, the trainer's
+    ``loss_fn(params, images, labels)``, the jitted SGD step
+    ``step_fn(params, opt, images, labels) -> (params, opt, loss,
+    metrics)``, its initial state and the dataset feeding it."""
+    arch: str
+    plan: object
+    loss_fn: object
+    step_fn: object
+    params: dict
+    opt: dict
+    dataset: ImageDataset
+    batch: int
+
+    def batch_at(self, step: int):
+        hb = self.dataset.batch_at(step)
+        return jnp.asarray(hb["images"]), jnp.asarray(hb["labels"])
+
+
+def setup_cnn(args) -> CNNRun:
+    """Build the CNN trainer: config, params, plan, the planned trunk and
+    the jitted SGD step."""
     import importlib
     mod = importlib.import_module(f"repro.configs.{args.arch}")
     ccfg = mod.reduced() if args.preset == "reduced" else mod.CONFIG
@@ -303,7 +326,6 @@ def train_cnn(args):
         return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
 
     opt_cfg = SGDConfig(lr=args.lr if args.lr != 3e-4 else 0.05)
-    opt = sgd_init(params)
 
     @jax.jit
     def step_fn(p, opt, images, labels):
@@ -315,34 +337,41 @@ def train_cnn(args):
         h=ccfg.image, w=ccfg.image, c=ccfg.channels,
         n_classes=ccfg.n_classes, batch=batch,
         seed=args.seed))
+    return CNNRun(ccfg.arch, plan, loss_fn, step_fn, params,
+                  sgd_init(params), ds, batch)
+
+
+def train_cnn(args):
+    run = setup_cnn(args)
+    params, opt = run.params, run.opt
     os.makedirs(args.out, exist_ok=True)
     steplog = StepLog("train")
     audit = None
     t0 = time.time()
     for step in range(args.steps):
-        hb = ds.batch_at(step)
-        images = jnp.asarray(hb["images"])
-        labels = jnp.asarray(hb["labels"])
+        images, labels = run.batch_at(step)
         if step == 0:
-            audit = _audit_step(step_fn, plan,
-                                {"arch": ccfg.arch, "batch": batch},
+            audit = _audit_step(run.step_fn, run.plan,
+                                {"arch": run.arch, "batch": run.batch},
                                 params, opt, images, labels)
-        params, opt, loss, m = step_fn(params, opt, images, labels)
+        params, opt, loss, m = run.step_fn(params, opt, images, labels)
         if step % args.log_every == 0 or step == args.steps - 1:
             steplog.log({"step": step, "loss": float(loss),
                          "elapsed_s": round(time.time() - t0, 1)})
     steplog.dump(os.path.join(args.out, "train_log.json"),
-                 arch=ccfg.arch, mode="cnn", plan=plan.to_dict(),
+                 arch=run.arch, mode="cnn", plan=run.plan.to_dict(),
                  plan_audit=audit)
     return steplog.records
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--preset", default="reduced", choices=["reduced", "full"])
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="global batch (default: the CNN config's own, "
+                         "8 for LM archs)")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
@@ -378,7 +407,12 @@ def main():
     from repro.exec.plancache import add_plan_cache_arg
     add_plan_cache_arg(ap)
     add_obs_args(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     configure_from_args(args, tool="train", arch=args.arch,
                         preset=args.preset)
     try:

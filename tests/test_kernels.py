@@ -60,7 +60,7 @@ def test_ssd_scan_allclose(ssd_case):
 
 def test_ssd_vmem_budget():
     from repro.kernels.ssd_chunk import vmem_bytes as ssd_vmem
-    assert ssd_vmem(128, 8, 64, 64) < 16 * 2**20
+    assert ssd_vmem(128, 64, 64) < 16 * 2**20
 
 
 def test_vmem_budget():

@@ -48,7 +48,7 @@ MODS, PARAMS = init_vgg16(KEY, SHAPE, width_mult=0.125, n_classes=4,
                           n_stages=2)
 X = jax.random.normal(jax.random.PRNGKey(1), (BATCH, H, H, 3))
 #: interpret pinned True so the tier is TPU-host-proof (CPU CI is the
-#: default resolution anyway; see repro.kernels.ops.default_interpret)
+#: platform default anyway; see repro.kernels.resolve_interpret)
 PALLAS = KernelSpec(backend="pallas", interpret=True)
 
 
@@ -355,16 +355,20 @@ def test_kernel_spec_validates():
 
 
 def test_interpret_env_override(monkeypatch):
+    """Interpret mode follows the platform, or a KernelSpec's explicit
+    flag: nothing in the environment can force the interpreter onto a
+    TPU."""
+    from repro import kernels
     from repro.kernels import ops
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert ops.default_interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops.default_interpret() is True
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    assert ops.default_interpret() is (jax.default_backend() != "tpu")
-    assert ops.resolve_interpret(None) == ops.default_interpret()
+    assert ops.resolve_interpret is kernels.resolve_interpret
+    assert ops.resolve_interpret(KernelSpec(backend="pallas").interpret) \
+        is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False        # TPU: compiled
+    assert ops.resolve_interpret(PALLAS.interpret) is True  # spec wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops.resolve_interpret(None) is True         # CPU: interpreter
     assert ops.resolve_interpret(False) is False
-    assert ops.resolve_interpret(True) is True
 
 
 # ---------------------------------------------------------------------------
