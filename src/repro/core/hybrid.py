@@ -24,6 +24,7 @@ from typing import List, Sequence, Tuple
 
 import jax
 
+from repro import obs
 from repro.core import overlap as _ov
 from repro.core import twophase as _tp
 from repro.models.cnn.layers import trunk_heights
@@ -97,8 +98,9 @@ def make_hybrid_apply(modules: Sequence, h0: int,
         seg_fns.append((spec, fn))
 
     def apply(params, x):
-        for spec, fn in seg_fns:
-            x = fn(params[spec.start:spec.end], x)
+        for i, (spec, fn) in enumerate(seg_fns):
+            with obs.scope("seg", tick=i):
+                x = fn(params[spec.start:spec.end], x)
         return x
 
     return apply

@@ -27,6 +27,7 @@ from typing import List, Sequence, Tuple
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core.convmath import Interval, split_even
 from repro.models.cnn.layers import trunk_heights
 
@@ -165,7 +166,8 @@ def _run_row(modules, params, plan: TwoPhasePlan, r: int, x_r, caches_in):
             head_n = own_lo - in_iv[0]
             if head_n > 0:
                 head = caches_in[l - 2]  # level l-1 import: cache_head(l, r)
-                x_in = jnp.concatenate([head, own], axis=1)
+                with obs.scope("sd_import"):
+                    x_in = jnp.concatenate([head, own], axis=1)
             else:
                 x_in = own
         y = m.apply_row(params[l - 1], x_in, in_iv, hs[l - 1], out_iv)
@@ -177,7 +179,9 @@ def _run_row(modules, params, plan: TwoPhasePlan, r: int, x_r, caches_in):
             nhi = plan.bounds[l - 1][r + 1]
             off = nlo - plan.bounds[l - 1][r]
             assert off >= 0, (l, r, nlo, plan.bounds[l - 1][r])
-            caches_out.append(lax.slice_in_dim(act, off, off + (nhi - nlo), axis=1))
+            with obs.scope("sd_export"):
+                caches_out.append(
+                    lax.slice_in_dim(act, off, off + (nhi - nlo), axis=1))
         act = y
         act_lo = out_iv[0]
     return act, caches_out

@@ -151,12 +151,11 @@ class _PipelineBase(RowProgram):
             r = t - s
             if not 0 <= r < N:
                 continue
-            if trace:
-                obs.span("stage_row", tick=t, stage=s, row=r,
-                         n_stages=S, n_rows=N)
+            with obs.scope("stage_row", tick=t, stage=s, row=r,
+                           n_stages=S, n_rows=N):
                 obs.counter("pipeline.stage_rows").inc()
-            y = xr if s == 0 else carry[s - 1]
-            y = self._stage_apply(params, y, s, r)
+                y = xr if s == 0 else carry[s - 1]
+                y = self._stage_apply(params, y, s, r)
             if s == S - 1:
                 y_out = y
             else:

@@ -180,21 +180,20 @@ def _tree_bytes(tree) -> int:
 def rowprog_forward(prog: RowProgram, args, collect: bool = False):
     """Plain forward sweep.  With ``collect`` also returns the carry
     entering each row (the boundary caches residency governs)."""
-    trace = obs.enabled()
     carry = prog.init_carry(args)
     ys, carries_in = [], []
     for r in range(prog.n_rows):
         if collect:
             carries_in.append(carry)
-        if trace:
-            # fires once per row at trace time; jit caches the trace, so
-            # the compiled step is identical with obs on or off
-            obs.span("fp_row", tick=r, n_rows=prog.n_rows,
-                     carry_bytes=_tree_bytes(carry))
+        # the scope sits here, not in row_step, so the BP replay of a row
+        # is not tagged as forward work
+        with obs.scope("fp_row", tick=r, n_rows=prog.n_rows,
+                       carry_bytes=_tree_bytes(carry)):
             obs.counter("rowprog.fp_rows").inc()
-        carry, y = prog.row_step(carry, prog.row_args(args, r), r)
+            carry, y = prog.row_step(carry, prog.row_args(args, r), r)
         ys.append(y)
-    out = prog.finish(ys)
+    with obs.scope("fp_merge"):
+        out = prog.finish(ys)
     out = (carry, out) if prog.returns_carry else out
     if collect:
         return out, carries_in
@@ -266,6 +265,19 @@ def make_rowprog_apply(prog: RowProgram,
             treedef, [to_device(l) if p == "host" else l
                       for l, p in zip(leaves, placements)])
 
+    def _trace_prefetch(saved_rr, r, rr):
+        placements = _placements(saved_rr, rr)
+        if "host" not in placements:
+            return
+        host_bytes = sum(_tree_bytes(l) for l, p in
+                         zip(jax.tree.leaves(saved_rr), placements)
+                         if p == "host")
+        # depth = how many rows ahead of consumption the copy is issued
+        # (0 = demand fetch)
+        obs.event("prefetch", tick=r, row=rr, depth=r - rr, bytes=host_bytes)
+        obs.counter("rowprog.prefetches").inc()
+        obs.counter("rowprog.prefetch_bytes").inc(host_bytes)
+
     def _row_recomputes(saved, r) -> bool:
         return any(p == "recompute" for p in _placements(saved, r))
 
@@ -285,14 +297,13 @@ def make_rowprog_apply(prog: RowProgram,
         ``upto``.  Serialized behind ``dep`` (the gradient carry of the
         row above) with an optimization barrier so XLA cannot run the N
         chains concurrently and re-materialize every cache at once."""
-        if jax.tree.leaves(dep):
-            args, _ = lax.optimization_barrier((args, dep))
-        if obs.enabled():
-            obs.event("recompute_chain", tick=upto, rows=upto)
+        with obs.scope("recompute_chain", tick=upto, rows=upto):
             obs.counter("rowprog.recompute_rows").inc(upto)
-        carry = prog.init_carry(args)
-        for rr in range(upto):
-            carry, _ = prog.row_step(carry, prog.row_args(args, rr), rr)
+            if jax.tree.leaves(dep):
+                args, _ = lax.optimization_barrier((args, dep))
+            carry = prog.init_carry(args)
+            for rr in range(upto):
+                carry, _ = prog.row_step(carry, prog.row_args(args, rr), rr)
         return carry
 
     @jax.custom_vjp
@@ -301,7 +312,8 @@ def make_rowprog_apply(prog: RowProgram,
 
     def fwd(*args):
         out, carries_in = rowprog_forward(prog, args, collect=True)
-        saved = tuple(_place(c, r) for r, c in enumerate(carries_in))
+        with obs.scope("place"):
+            saved = tuple(_place(c, r) for r, c in enumerate(carries_in))
         return out, (args, saved)
 
     def bwd(residuals, g):
@@ -320,43 +332,42 @@ def make_rowprog_apply(prog: RowProgram,
         trace = obs.enabled()
         fetched = {}
         for r in range(prog.n_rows - 1, -1, -1):
-            for rr in range(r, max(-1, r - 1 - res.prefetch_depth), -1):
-                if rr not in fetched:
-                    fetched[rr] = _fetch(saved[rr], rr, dcarry)
-                    placements = _placements(saved[rr], rr)
-                    if trace and "host" in placements:
-                        host_bytes = sum(
-                            _tree_bytes(l) for l, p in
-                            zip(jax.tree.leaves(saved[rr]), placements)
-                            if p == "host")
-                        # depth = how many rows ahead of consumption the
-                        # copy is issued (0 = demand fetch)
-                        obs.event("prefetch", tick=r, row=rr, depth=r - rr,
-                                  bytes=host_bytes)
-                        obs.counter("rowprog.prefetches").inc()
-                        obs.counter("rowprog.prefetch_bytes").inc(host_bytes)
-            carry_in = fetched.pop(r)
-            if trace:
-                obs.span("bp_row", tick=r, n_rows=prog.n_rows,
-                         recomputes=_row_recomputes(saved[r], r))
+            recomputes = _row_recomputes(saved[r], r)
+            with obs.scope("bp_row", tick=r, n_rows=prog.n_rows,
+                           recomputes=recomputes):
                 obs.counter("rowprog.bp_rows").inc()
-            if _row_recomputes(saved[r], r):
-                carry_in = _merge_recomputed(
-                    carry_in, _recompute_chain(args, r, dcarry), r)
+                with obs.scope("fetch"):
+                    for rr in range(r, max(-1, r - 1 - res.prefetch_depth),
+                                    -1):
+                        if rr not in fetched:
+                            fetched[rr] = _fetch(saved[rr], rr, dcarry)
+                            if trace:
+                                _trace_prefetch(saved[rr], r, rr)
+                carry_in = fetched.pop(r)
+                if recomputes:
+                    carry_in = _merge_recomputed(
+                        carry_in, _recompute_chain(args, r, dcarry), r)
 
-            def step_r(c, ra, r=r):
-                return prog.row_step(c, ra, r)
+                def step_r(c, ra, r=r):
+                    return prog.row_step(c, ra, r)
 
-            # one vjp trace of the slicing yields both the row's args and
-            # the scatter transpose that routes their gradients back
-            row_args, slice_vjp = jax.vjp(
-                lambda a, r=r: prog.row_args(a, r), args)
-            (carry_out, _y), vjp = jax.vjp(step_r, carry_in, row_args)
-            if dcarry is None:  # no carry cotangent flows into the last row
-                dcarry = jax.tree.map(jnp.zeros_like, carry_out)
-            dcin, drow = vjp((dcarry, prog.out_cotangent(g_out, r)))
-            dargs = jax.tree.map(jnp.add, dargs, slice_vjp(drow)[0])
-            dcarry = dcin
+                with obs.scope("replay"):
+                    # one vjp trace of the slicing yields both the row's
+                    # args and the scatter transpose that routes their
+                    # gradients back
+                    row_args, slice_vjp = jax.vjp(
+                        lambda a, r=r: prog.row_args(a, r), args)
+                    (carry_out, _y), vjp = jax.vjp(step_r, carry_in,
+                                                   row_args)
+                if dcarry is None:  # no carry cotangent enters the last row
+                    dcarry = jax.tree.map(jnp.zeros_like, carry_out)
+                with obs.scope("vjp"):
+                    dcin, drow = vjp((dcarry, prog.out_cotangent(g_out, r)))
+                # the add is inside the scope: a pad-and-add fusion takes
+                # the metadata of its root, the add
+                with obs.scope("grad_scatter"):
+                    dargs = jax.tree.map(jnp.add, dargs, slice_vjp(drow)[0])
+                dcarry = dcin
         # close the chain through init_carry (e.g. the scan's carry_init)
         _, init_vjp = jax.vjp(lambda a: prog.init_carry(a), args)
         dargs = jax.tree.map(jnp.add, dargs, init_vjp(dcarry)[0])

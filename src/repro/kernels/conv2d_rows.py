@@ -129,6 +129,7 @@ def conv2d_rows(x, w, *, stride: int = 1, padding: int = 0,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=resolve_interpret(interpret),
+        name="conv2d_rows",
     )(x, x, w)
     if pad_out:
         out = out[:, :H_out]

@@ -109,6 +109,7 @@ def ssd_scan(x, B, C, a, dt, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((Bt, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="ssd_chunk",
     )(xh, B, C, gates, gates.transpose(0, 1, 3, 2))
     return out.transpose(0, 2, 1, 3)
 

@@ -128,5 +128,6 @@ def swa_attention(q, k, v, *, window: int, bq: int = 128, bk: int = 128,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="swa_attention",
     )(qf, kp, vp)
     return out.reshape(B, H, S, D)

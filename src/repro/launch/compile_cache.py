@@ -22,8 +22,13 @@ def enable_compile_cache() -> str:
     """Turn on the persistent compilation cache and return its directory.
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
-    nothing is set here; otherwise the cache goes to ``.jax_cache/`` at
-    the repository root."""
+    no directory is set here; otherwise the cache goes to ``.jax_cache/``
+    at the repository root.  Either way the cache key includes the
+    program's debug metadata: JAX strips it from the key by default, so a
+    step compiled before its ``obs.scope`` names changed would be loaded
+    with the old op names, and the device trace would attribute its ops
+    to scopes the program no longer has."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -111,6 +111,24 @@ def _audit_step(step_fn, plan, source_extra, *step_args,
     return rec
 
 
+def _lm_batch(ds, cfg, args, step: int) -> dict:
+    """The LM trainer's batch for ``step``, on the device."""
+    hb = ds.batch_at(step)
+    batch = {"tokens": jnp.asarray(hb["tokens"]),
+             "labels": jnp.asarray(hb["labels"])}
+    if cfg.family == "vlm":
+        batch["patch_embeds"] = jnp.zeros(
+            (args.batch, cfg.n_frontend_tokens, cfg.frontend_dim),
+            jnp.float32)
+    if cfg.family == "encdec":
+        batch = {"frames": jnp.asarray(
+                    np.random.default_rng((args.seed, step)).normal(
+                        0, 1, (args.batch, args.seq, cfg.d_model))
+                    .astype(np.float32)),
+                 "tokens": batch["tokens"], "labels": batch["labels"]}
+    return batch
+
+
 def train_lm(args):
     if args.batch is None:
         args.batch = 8
@@ -184,50 +202,44 @@ def train_lm(args):
     audit = None
     t0 = time.time()
     for step in range(args.steps):
-        hb = ds.batch_at(step)
-        batch = {"tokens": jnp.asarray(hb["tokens"]),
-                 "labels": jnp.asarray(hb["labels"])}
-        if cfg.family == "vlm":
-            batch["patch_embeds"] = jnp.zeros(
-                (args.batch, cfg.n_frontend_tokens, cfg.frontend_dim),
-                jnp.float32)
-        if cfg.family == "encdec":
-            batch = {"frames": jnp.asarray(
-                        np.random.default_rng((args.seed, step)).normal(
-                            0, 1, (args.batch, args.seq, cfg.d_model))
-                        .astype(np.float32)),
-                     "tokens": batch["tokens"], "labels": batch["labels"]}
-        if step == 0:
-            # audit before the first call: donated state buffers are
-            # still live, and lowering only reads avals anyway.  The plan
-            # prices the activation / sequence-chunk term; adding the
-            # paper's ξ (params + grads + optimizer moments, all fp32
-            # beside the activations) makes the estimate comparable to
-            # the step's measured peak, so train_step_lm is a gated
-            # source now that the plan is what actually executes
-            est = None
-            if plan is not None:
-                xi = 4 * sum(l.nbytes
-                             for l in jax.tree.leaves(state["params"]))
-                est = plan.est_bytes_per_device + xi
-            audit = _audit_step(step_fn, plan,
-                                {"arch": cfg.name, "batch": args.batch,
-                                 "seq": args.seq}, state, batch,
-                                source="train_step_lm", est_bytes=est)
-        state, metrics = step_fn(state, batch)
-        if step == 0:
-            # step 0 pays the compile: log it separately and restart the
-            # clock so elapsed_s tracks steady-state step time
-            jax.block_until_ready(metrics)
-            compile_s = round(time.time() - t0, 1)
-            t0 = time.time()
-        if step % args.log_every == 0 or step == args.steps - 1:
-            m = {k: float(v) for k, v in metrics.items()}
-            m["step"] = step
-            m["elapsed_s"] = round(time.time() - t0, 1)
+        with jax.profiler.StepTraceAnnotation("train_step", step_num=step):
+            with obs.scope("data"):
+                batch = _lm_batch(ds, cfg, args, step)
             if step == 0:
-                m["compile_s"] = compile_s
-            steplog.log(m)
+                # audit before the first call: donated state buffers are
+                # still live, and lowering only reads avals anyway.  The
+                # plan prices the activation / sequence-chunk term;
+                # adding the paper's ξ (params + grads + optimizer
+                # moments, all fp32 beside the activations) makes the
+                # estimate comparable to the step's measured peak, so
+                # train_step_lm is a gated source now that the plan is
+                # what actually executes
+                est = None
+                if plan is not None:
+                    xi = 4 * sum(l.nbytes
+                                 for l in jax.tree.leaves(state["params"]))
+                    est = plan.est_bytes_per_device + xi
+                audit = _audit_step(step_fn, plan,
+                                    {"arch": cfg.name, "batch": args.batch,
+                                     "seq": args.seq}, state, batch,
+                                    source="train_step_lm", est_bytes=est)
+            with obs.scope("dispatch"):
+                state, metrics = step_fn(state, batch)
+            if step == 0:
+                # step 0 pays the compile: log it separately and restart
+                # the clock so elapsed_s tracks steady-state step time
+                with obs.scope("sync"):
+                    jax.block_until_ready(metrics)
+                compile_s = round(time.time() - t0, 1)
+                t0 = time.time()
+            if step % args.log_every == 0 or step == args.steps - 1:
+                with obs.scope("sync"):
+                    m = {k: float(v) for k, v in metrics.items()}
+                m["step"] = step
+                m["elapsed_s"] = round(time.time() - t0, 1)
+                if step == 0:
+                    m["compile_s"] = compile_s
+                steplog.log(m)
     if args.save:
         # sharded leaves save per-shard; the executed plan rides along as
         # a JSON sidecar so the checkpoint replays its own policy
@@ -320,17 +332,20 @@ def setup_cnn(args) -> CNNRun:
           f"params={n_params/1e6:.1f}M image={ccfg.image}")
 
     def loss_fn(p, images, labels):
-        feats = trunk_apply(p["trunk"], images)
-        logits = head_apply(p["head"], feats)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
+        with obs.scope("trunk"):
+            feats = trunk_apply(p["trunk"], images)
+        with obs.scope("head_loss"):
+            logits = head_apply(p["head"], feats)
+            logp = jax.nn.log_softmax(logits)
+            return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
 
     opt_cfg = SGDConfig(lr=args.lr if args.lr != 3e-4 else 0.05)
 
     @jax.jit
     def step_fn(p, opt, images, labels):
         loss, g = jax.value_and_grad(loss_fn)(p, images, labels)
-        p, opt, m = sgd_update(p, g, opt, opt_cfg)
+        with obs.scope("sgd_update"):
+            p, opt, m = sgd_update(p, g, opt, opt_cfg)
         return p, opt, loss, m
 
     ds = ImageDataset(ImageDatasetConfig(
@@ -349,15 +364,21 @@ def train_cnn(args):
     audit = None
     t0 = time.time()
     for step in range(args.steps):
-        images, labels = run.batch_at(step)
-        if step == 0:
-            audit = _audit_step(run.step_fn, run.plan,
-                                {"arch": run.arch, "batch": run.batch},
-                                params, opt, images, labels)
-        params, opt, loss, m = run.step_fn(params, opt, images, labels)
-        if step % args.log_every == 0 or step == args.steps - 1:
-            steplog.log({"step": step, "loss": float(loss),
-                         "elapsed_s": round(time.time() - t0, 1)})
+        with jax.profiler.StepTraceAnnotation("train_step", step_num=step):
+            with obs.scope("data"):
+                images, labels = run.batch_at(step)
+            if step == 0:
+                audit = _audit_step(run.step_fn, run.plan,
+                                    {"arch": run.arch, "batch": run.batch},
+                                    params, opt, images, labels)
+            with obs.scope("dispatch"):
+                params, opt, loss, m = run.step_fn(params, opt, images,
+                                                   labels)
+            if step % args.log_every == 0 or step == args.steps - 1:
+                with obs.scope("sync"):
+                    loss = float(loss)
+                steplog.log({"step": step, "loss": loss,
+                             "elapsed_s": round(time.time() - t0, 1)})
     steplog.dump(os.path.join(args.out, "train_log.json"),
                  arch=run.arch, mode="cnn", plan=run.plan.to_dict(),
                  plan_audit=audit)

@@ -9,14 +9,27 @@ One module-level session gates everything:
     ...
     obs.shutdown()          # writes the metrics dump, closes the trace
 
-Instrumentation sites call :func:`emit` / :func:`counter` / :func:`gauge`
-/ :func:`histogram` unconditionally.  When no session is active,
-``emit`` returns immediately and the metric constructors hand back the
-shared :data:`~repro.obs.metrics.NULL_METRIC` no-op — so a disabled run
-pays one attribute load and one truthiness check per call site, and
-*nothing* inside a jitted path: the executor hooks fire at trace time
-only (jit caches the trace), so the compiled step function is
-byte-identical with obs on or off.
+Instrumentation sites call :func:`scope` / :func:`emit` /
+:func:`counter` / :func:`gauge` / :func:`histogram` unconditionally.
+
+:func:`scope` is the one span primitive.  It names a block of work in
+every trace that can see it, by one name (the span's name with its tick
+appended, ``fp_row3``):
+
+* inside jit tracing, ``jax.named_scope`` tags every op traced in the
+  block, so the compiled step's HLO metadata (``op_name``) and hence the
+  device ops of a profiler trace say which segment, row and backward
+  phase they belong to — metadata only, the compiled ops are the same;
+* ``jax.profiler.TraceAnnotation`` puts a host span into any running
+  profiler trace (trace time inside jit, run time in the launch loops);
+* with a session open, a ``span`` record with its start ``t_ns`` and
+  ``dur_ns`` on ``time.perf_counter_ns()`` goes to the tracer.
+
+When no session is active, ``emit`` returns immediately, ``scope``
+records nothing, and the metric constructors hand back the shared
+:data:`~repro.obs.metrics.NULL_METRIC` no-op.  The row executors' hooks
+fire at trace time (jit caches the trace), so their records describe the
+traced program once, not every step.
 
 Registration is one call per layer (see ROADMAP "Observability"):
 the row-program executor, the serve scheduler and the launch CLIs all
@@ -29,15 +42,17 @@ from __future__ import annotations
 import contextlib
 from typing import Optional
 
+import jax
+
 from repro.obs.metrics import (METRICS_SCHEMA, Counter, Gauge, Histogram,
-                               MetricsRegistry, NULL_METRIC, merge_counts)
+                               MetricsRegistry, NULL_METRIC)
 from repro.obs.trace import TRACE_SCHEMA, Tracer, read_jsonl
 
 __all__ = [
     "configure", "shutdown", "enabled", "session", "capture",
-    "emit", "span", "event", "counter", "gauge", "histogram",
+    "scope", "emit", "span", "event", "counter", "gauge", "histogram",
     "Tracer", "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "NULL_METRIC", "merge_counts", "read_jsonl",
+    "NULL_METRIC", "read_jsonl",
     "TRACE_SCHEMA", "METRICS_SCHEMA",
 ]
 
@@ -109,6 +124,24 @@ def emit(kind: str, name: str, tick=None, **attrs) -> None:
     s = _session
     if s is not None:
         s.tracer.emit(kind, name, tick, **attrs)
+
+
+@contextlib.contextmanager
+def scope(name: str, tick=None, **attrs):
+    """Name the enclosed work ``name`` (``f"{name}{tick}"`` with a tick)
+    in the compiled HLO's op metadata and in any running profiler trace,
+    and, with a session open, record it as a timed ``span``."""
+    label = name if tick is None else f"{name}{tick}"
+    with jax.named_scope(label), jax.profiler.TraceAnnotation(label):
+        s = _session
+        if s is None:
+            yield
+            return
+        rec = s.tracer.open_span(name, tick, **attrs)
+        try:
+            yield
+        finally:
+            s.tracer.close_span(rec)
 
 
 def span(name: str, tick=None, **attrs) -> None:

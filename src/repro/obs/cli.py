@@ -5,16 +5,19 @@ same three flags:
 
   --trace PATH        write the span/event/audit stream as JSONL
   --metrics-out PATH  write the metrics-registry dump on exit
-  --jax-profile DIR   also capture a jax.profiler trace into DIR
+  --jax-profile DIR   capture a jax.profiler trace into DIR
 
 Passing either of the first two opens the module-level obs session; with
 neither, the session stays closed and every hook in the executors is a
-no-op (the zero-overhead default).
+no-op (the zero-overhead default).  ``--jax-profile`` needs no session:
+the ``obs.scope`` spans reach the profiler trace on their own.
 """
 
 from __future__ import annotations
 
 import contextlib
+
+import jax
 
 from repro import obs
 
@@ -29,8 +32,9 @@ def add_obs_args(ap) -> None:
                          "gauges / histogram summaries) to this path on "
                          "exit")
     ap.add_argument("--jax-profile", default="",
-                    help="also capture a jax.profiler trace into this "
-                         "directory (requires --trace or --metrics-out)")
+                    help="capture a jax.profiler trace into this "
+                         "directory; with --trace, its obs_anchor span "
+                         "maps the JSONL's t_ns onto the profile's clock")
 
 
 def configure_from_args(args, **meta) -> bool:
@@ -44,16 +48,20 @@ def configure_from_args(args, **meta) -> bool:
 
 @contextlib.contextmanager
 def profiled(args):
-    """jax.profiler capture scoped over the run when --jax-profile is
-    set (and obs is on — profiling without a sink to cross-reference
-    would be unanchored)."""
-    active = bool(getattr(args, "jax_profile", "")) and obs.enabled()
+    """jax.profiler capture scoped over the run when --jax-profile is set.
+
+    The capture opens with one ``obs_anchor`` span, written into the
+    profile and, with a session open, into the JSONL trace: the profile's
+    host events are timed from the capture's start, so the anchor's
+    ``t_ns`` minus its start in the profile maps every JSONL ``t_ns``
+    onto the profile's timeline."""
+    active = bool(getattr(args, "jax_profile", ""))
     if active:
-        import jax
         jax.profiler.start_trace(args.jax_profile)
+        with obs.scope("obs_anchor"):
+            pass
     try:
         yield
     finally:
         if active:
-            import jax
             jax.profiler.stop_trace()

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 #: version of the metrics-dump JSON layout (bump on breaking change)
 METRICS_SCHEMA = 1
@@ -152,11 +152,3 @@ class MetricsRegistry:
                              f"this reader understands {METRICS_SCHEMA}")
         return d
 
-
-def merge_counts(registry: MetricsRegistry,
-                 counts: Optional[dict]) -> None:
-    """Bulk-add a ``{name: n}`` mapping into the registry's counters —
-    the bridge for components that tally locally (the scheduler's event
-    counts) and flush once."""
-    for name, n in (counts or {}).items():
-        registry.counter(name).inc(int(n))

@@ -1,4 +1,4 @@
-"""Tick-denominated span tracer with a schema-versioned JSONL sink.
+"""Span tracer with a schema-versioned JSONL sink.
 
 Every record is one JSON object per line.  The first line of a trace
 file is a ``header`` record pinning the schema version and the run
@@ -6,28 +6,32 @@ metadata (arch, engine, plan digest — whatever :func:`repro.obs.configure`
 was given); every subsequent line is one of
 
   ``span``        a named unit of work at a tick (fp_row / bp_row /
-                  decode_cohort / train_step ...), with free-form attrs
+                  train_step ...), with free-form attrs
   ``event``       a point occurrence (offload / prefetch / admit /
                   preempt / page_grow ...), same shape as a span
   ``plan_audit``  a measured-vs-estimated peak-bytes record (see
                   :mod:`repro.obs.audit`)
 
-"Tick" is whatever clock the emitting layer is denominated in — the row
+"Tick" is whatever index the emitting layer is denominated in — the row
 index inside the row-program executor, the scheduler tick in serve, the
-optimiser step in train.  Wall-clock timestamps are deliberately *not*
-part of the schema: the repo's executors are deterministic in ticks, so
-two runs of the same config produce byte-identical traces, which is what
-lets CI diff them.
+optimiser step in train.  A span opened by :func:`repro.obs.scope` also
+carries ``t_ns`` (its start on ``time.perf_counter_ns()``) and ``dur_ns``.
+The profiler's host events share that clock up to one offset, which the
+``obs_anchor`` span that :func:`repro.obs.cli.profiled` writes into both
+traces gives.  Spans recorded by :meth:`Tracer.span` and events carry no
+time, so those parts of two runs of one config still diff cleanly.
 
 The in-memory ``records`` list is always kept (tests and
-``ServeReport.timeline()`` read it); the JSONL file is written only when
-a path is given.
+``ServeReport.timeline()`` read it), in the order spans were opened; the
+JSONL file is written only when a path is given, and a record reaches it
+once every span open at its creation has closed.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import time
 from typing import List, Optional
 
 #: version of the trace-record layout (bump on breaking change)
@@ -40,6 +44,8 @@ class Tracer:
     def __init__(self, path: Optional[str] = None, meta: Optional[dict] = None):
         self.path = path
         self.records: List[dict] = []
+        self._n_open = 0
+        self._n_written = 0
         if path and os.path.dirname(path):
             os.makedirs(os.path.dirname(path), exist_ok=True)
         self._fh = open(path, "w") if path else None
@@ -49,10 +55,18 @@ class Tracer:
 
     def _write(self, rec: dict) -> None:
         self.records.append(rec)
-        if self._fh is not None:
-            self._fh.write(json.dumps(rec, sort_keys=True) + "\n")
+        self._drain()
 
-    def emit(self, kind: str, name: str, tick=None, **attrs) -> None:
+    def _drain(self) -> None:
+        """Write every record whose spans have all closed."""
+        if self._fh is None or self._n_open:
+            return
+        for rec in self.records[self._n_written:]:
+            self._fh.write(json.dumps(rec, sort_keys=True) + "\n")
+        self._n_written = len(self.records)
+
+    @staticmethod
+    def _record(kind: str, name: str, tick, attrs: dict) -> dict:
         rec = {"kind": kind, "name": name}
         if tick is not None:
             # row/step ticks are ints; scheduler ticks may be fractional
@@ -61,7 +75,10 @@ class Tracer:
             rec["tick"] = int(t) if t.is_integer() else t
         if attrs:
             rec["attrs"] = attrs
-        self._write(rec)
+        return rec
+
+    def emit(self, kind: str, name: str, tick=None, **attrs) -> None:
+        self._write(self._record(kind, name, tick, attrs))
 
     def span(self, name: str, tick=None, **attrs) -> None:
         self.emit("span", name, tick, **attrs)
@@ -69,14 +86,26 @@ class Tracer:
     def event(self, name: str, tick=None, **attrs) -> None:
         self.emit("event", name, tick, **attrs)
 
+    def open_span(self, name: str, tick=None, **attrs) -> dict:
+        """Record a span starting now; :meth:`close_span` gives it its
+        duration."""
+        rec = self._record("span", name, tick, attrs)
+        rec["t_ns"] = time.perf_counter_ns()
+        self._n_open += 1
+        self._write(rec)
+        return rec
+
+    def close_span(self, rec: dict) -> None:
+        rec["dur_ns"] = time.perf_counter_ns() - rec["t_ns"]
+        self._n_open -= 1
+        self._drain()
+
     def close(self) -> None:
         if self._fh is not None:
+            self._n_open = 0
+            self._drain()
             self._fh.close()
             self._fh = None
-
-    def flush(self) -> None:
-        if self._fh is not None:
-            self._fh.flush()
 
 
 def read_jsonl(path: str) -> List[dict]:

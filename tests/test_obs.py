@@ -382,3 +382,83 @@ def test_load_steps_reads_pre_schema_bare_list(tmp_path):
     path = tmp_path / "old.json"
     path.write_text(json.dumps([{"step": 0, "loss": 2.0}]))
     assert load_steps(str(path)) == [{"step": 0, "loss": 2.0}]
+
+
+# ---------------------------------------------------------------------------
+# obs.scope: timed spans, HLO op names, the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_scope_records_timed_spans_in_open_order(tmp_path):
+    path = str(tmp_path / "t.jsonl")
+    with obs.capture(trace=path) as s:
+        with obs.scope("bp_row", tick=3, n_rows=4):
+            with obs.scope("vjp"):
+                pass
+            # a span reaches the file once it has its duration
+            s.tracer._fh.flush()
+            assert open(path).read().count("\n") == 1  # the header
+        recs = s.tracer.records[1:]
+    assert [r["name"] for r in recs] == ["bp_row", "vjp"]
+    outer, inner = recs
+    assert outer["kind"] == "span" and outer["tick"] == 3
+    assert outer["attrs"] == {"n_rows": 4} and "attrs" not in inner
+    for r in recs:
+        assert isinstance(r["t_ns"], int) and r["dur_ns"] >= 0
+    assert outer["t_ns"] <= inner["t_ns"]
+    assert inner["t_ns"] + inner["dur_ns"] <= outer["t_ns"] + outer["dur_ns"]
+    assert read_jsonl(path)[1:] == recs
+
+
+def test_scope_without_session_records_nothing():
+    assert not obs.enabled()
+    with obs.scope("fp_row", tick=0, carry_bytes=8):
+        assert obs.counter("rowprog.fp_rows") is NULL_METRIC
+    assert obs.session() is None
+    with pytest.raises(ZeroDivisionError):
+        with obs.scope("sync"):
+            1 / 0
+
+
+def test_scope_names_the_ops_of_a_jitted_step():
+    def f(x):
+        with obs.scope("fp_row", tick=2):
+            y = jnp.sin(x)
+        with obs.scope("sgd_update"):
+            return y * 3.0
+
+    txt = jax.jit(f).lower(jnp.ones(4)).as_text(dialect="hlo",
+                                                 debug_info=True)
+    assert 'op_name="jit(f)/fp_row2/sin"' in txt
+    assert 'op_name="jit(f)/sgd_update/mul"' in txt
+
+
+def test_profiled_anchor_puts_jsonl_spans_on_the_profile_clock(tmp_path):
+    import time
+    import types
+
+    from repro.obs.cli import profiled
+
+    args = types.SimpleNamespace(jax_profile=str(tmp_path / "prof"))
+    names = ("obs_anchor", "data", "dispatch", "sync")
+    with obs.capture(trace=str(tmp_path / "t.jsonl")) as s:
+        with profiled(args):
+            for _ in range(3):
+                with obs.scope("data"):
+                    time.sleep(0.002)
+                with obs.scope("dispatch"):
+                    x = jnp.arange(64.0).sum()
+                with obs.scope("sync"):
+                    float(x)
+    spans = [r for r in read_jsonl(str(tmp_path / "t.jsonl"))
+             if r.get("name") in names]
+    (path,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    host = sorted((e.start_ns, e.name) for p in pd.planes
+                  if p.name.startswith("/host:") for ln in p.lines
+                  for e in ln.events if e.name in names)
+    assert [n for _, n in host] == [r["name"] for r in spans]
+    assert len(spans) == 10 and spans[0]["name"] == "obs_anchor"
+    shift = spans[0]["t_ns"] - host[0][0]
+    for (start, _), r in zip(host, spans):
+        assert abs(r["t_ns"] - shift - start) < 1e6
