@@ -364,9 +364,15 @@ def make_rowprog_apply(prog: RowProgram,
                 with obs.scope("vjp"):
                     dcin, drow = vjp((dcarry, prog.out_cotangent(g_out, r)))
                 # the add is inside the scope: a pad-and-add fusion takes
-                # the metadata of its root, the add
+                # the metadata of its root, the add.  The barrier keeps
+                # the transpose's pad out of the ops that make drow:
+                # XLA's TPU pipeline would fold a row's pad into its
+                # input-gradient convolution, which then runs at the
+                # full height of the segment input.
                 with obs.scope("grad_scatter"):
-                    dargs = jax.tree.map(jnp.add, dargs, slice_vjp(drow)[0])
+                    guarded = jax.tree.map(lax.optimization_barrier, drow)
+                    dargs = jax.tree.map(jnp.add, dargs,
+                                         slice_vjp(guarded)[0])
                 dcarry = dcin
         # close the chain through init_carry (e.g. the scan's carry_init)
         _, init_vjp = jax.vjp(lambda a: prog.init_carry(a), args)

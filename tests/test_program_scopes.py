@@ -55,10 +55,9 @@ def test_every_row_and_backward_phase_is_named(lowered):
             assert any(k[:3] == (f"seg{i}", "fp_row", r) for k in seen), \
                 (i, r)
             # the backward starts at the last row, whose scatter adds into
-            # zeros: the lowering folds that add away
-            phases = ("replay", "vjp") + (
-                ("grad_scatter",) if r < n_rows - 1 else ())
-            for phase in phases:
+            # zeros, an add the lowering folds; the barrier in front of
+            # every row's scatter still names the phase
+            for phase in ("replay", "vjp", "grad_scatter"):
                 assert (f"seg{i}", "bp_row", r, phase) in seen, (i, r, phase)
 
 

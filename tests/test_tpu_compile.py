@@ -12,15 +12,18 @@ worker imports every test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.hybrid import SegmentSpec, make_hybrid_apply
 from repro.kernels.conv2d_rows import conv2d_rows
 from repro.kernels.ssd_chunk import ssd_scan
 from repro.kernels.swa_attention import swa_attention
+from repro.models.cnn.layers import Conv, ReLU, init_trunk
 
 
 @pytest.fixture(scope="module")
@@ -87,3 +90,27 @@ def test_ssd_chunk_compiles_for_v5e(one_chip):
                           ((bt, s, n), f32), ((bt, s, n), f32),
                           ((bt, s, h), f32), ((bt, s, h), f32))
     assert "tpu_custom_call" in text
+
+
+def test_row_input_gradients_keep_their_window(one_chip):
+    """A 2PS row's input gradient is padded to the segment's height
+    behind a barrier.  Without it the TPU pipeline folds the pad into the
+    convolution that makes the gradient, which then runs at the
+    segment's full height (window padding up to 22_4 here): no 3x3
+    convolution of the compiled backward may pad by more than k - 1."""
+    mods = [Conv(128), ReLU(), Conv(128), ReLU()] * 2
+    params, _ = init_trunk(mods, jax.random.PRNGKey(0), (28, 28, 128))
+    apply = make_hybrid_apply(mods, 28, [SegmentSpec(0, 4, 7, "twophase"),
+                                         SegmentSpec(4, 8, 7, "twophase")])
+    shapes = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        params)
+    x = jax.ShapeDtypeStruct((8, 28, 28, 128), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(jax.grad(lambda p, x: apply(p, x).sum())).lower(
+        shapes, x).compile().as_text()
+    pads = re.findall(r" convolution\(.*window=\{size=3x3 pad=([\d_x-]+)",
+                      text)
+    assert pads
+    for pad in pads:
+        assert max(int(p) for p in re.findall(r"-?\d+", pad)) <= 2, pad
