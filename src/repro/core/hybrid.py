@@ -84,6 +84,10 @@ def make_hybrid_apply(modules: Sequence, h0: int,
     for spec in segments:
         sub = list(modules[spec.start:spec.end])
         h_in = hs[spec.start]
+        sd_rows = []
+        if spec.strategy == "twophase":
+            sd_rows = _tp.module_boundaries(sub, h_in,
+                                            spec.n_rows).cache_sizes()
         if spec.strategy == "column":
             fn = _ov.make_column_apply(sub)
             if len(segments) > 1 or spec.n_rows > 1:
@@ -95,10 +99,16 @@ def make_hybrid_apply(modules: Sequence, h0: int,
                                          residency=residency)
         else:
             raise ValueError(spec.strategy)
-        seg_fns.append((spec, fn))
+        seg_fns.append((spec, fn, sd_rows))
 
     def apply(params, x):
-        for i, (spec, fn) in enumerate(seg_fns):
+        for i, (spec, fn, sd_rows) in enumerate(seg_fns):
+            # trace-time record of the segment the plan runs; sd_rows[r-1]
+            # are the boundary-cache rows row r imports, per level
+            obs.event("segment", tick=i, start=spec.start, end=spec.end,
+                      n_rows=spec.n_rows, strategy=spec.strategy,
+                      sd_rows=sd_rows)
+            obs.counter("twophase.sd_rows").inc(sum(map(sum, sd_rows)))
             with obs.scope("seg", tick=i):
                 x = fn(params[spec.start:spec.end], x)
         return x

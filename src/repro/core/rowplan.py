@@ -38,9 +38,12 @@ def shape_chain(modules: Sequence, in_shape: Tuple[int, int, int]):
 
 def feature_bytes(modules: Sequence, in_shape, batch: int,
                   dtype_bytes: int = 4) -> List[int]:
-    """ϱ^l for l = 1..L (bytes)."""
+    """ϱ^l for l = 1..L (bytes).  A module whose widest activation never
+    shows in its output shape (a ConvNeXt block's 4C expansion) states its
+    channels per output pixel as ``held_channels``, and is priced at them."""
     shapes = shape_chain(modules, in_shape)
-    return [batch * h * w * c * dtype_bytes for (h, w, c) in shapes[1:]]
+    return [batch * h * w * getattr(m, "held_channels", c) * dtype_bytes
+            for m, (h, w, c) in zip(modules, shapes[1:])]
 
 
 def omega_column(modules, in_shape, batch, dtype_bytes: int = 4) -> int:

@@ -242,9 +242,16 @@ def audit_ratio_key(source: str, engine: str, residency: str,
 def _module_fwd_flops(m, sin: Tuple[int, int, int],
                       sout: Tuple[int, int, int], batch: int) -> float:
     h_out, w_out, c_out = sout
+    if hasattr(m, "parts"):  # Chain: its parts in turn
+        return trunk_fwd_flops(m.parts, sin, batch)
     if hasattr(m, "cout") and hasattr(m, "k") and hasattr(m, "init"):
-        # Conv: 2*k*k*Cin MACs per output element
-        return 2.0 * m.k * m.k * sin[2] * c_out * h_out * w_out * batch
+        # Conv: 2*k*k*Cin/groups MACs per output element
+        return (2.0 * m.k * m.k * (sin[2] // m.groups) * c_out * h_out
+                * w_out * batch)
+    if hasattr(m, "expansion"):
+        # ConvNeXt block: depthwise k x k, then 1x1 C->eC and eC->C
+        return (2.0 * (m.k * m.k + 2 * m.expansion * c_out) * c_out
+                * h_out * w_out * batch)
     if hasattr(m, "cmid"):
         # Bottleneck: 1x1 reduce at input spatial, 3x3 at output spatial,
         # 1x1 expand (+ projection shortcut when present)

@@ -56,6 +56,7 @@ from repro.data.pipeline import (
     ImageDataset, ImageDatasetConfig, TokenDataset, TokenDatasetConfig,
 )
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models.cnn import convnext, resnet, vgg
 from repro.obs.audit import measure_step, plan_audit
 from repro.obs.cli import add_obs_args, configure_from_args, profiled
 from repro.obs.steplog import StepLog
@@ -272,26 +273,39 @@ class CNNRun:
         return jnp.asarray(hb["images"]), jnp.asarray(hb["labels"])
 
 
+#: CNN arch -> ``(init(key, shape, width_mult, n_classes=, **options) ->
+#: (modules, params), head_apply(head, feats))``; ``options`` are the
+#: ``--layer-scale-init`` kind, which only some trunks take
+CNN_TRUNKS = {
+    "vgg16": (vgg.init_vgg16, vgg.head_apply),
+    "resnet50": (resnet.init_resnet50, resnet.head_apply),
+    "convnext_t": (convnext.init_convnext, convnext.head_apply),
+}
+
+
 def setup_cnn(args) -> CNNRun:
     """Build the CNN trainer: config, params, plan, the planned trunk and
     the jitted SGD step."""
     import importlib
+    if args.arch not in CNN_TRUNKS:
+        raise ValueError(f"unknown CNN arch {args.arch!r}; known: "
+                         f"{sorted(CNN_TRUNKS)}")
     mod = importlib.import_module(f"repro.configs.{args.arch}")
     ccfg = mod.reduced() if args.preset == "reduced" else mod.CONFIG
 
     from repro.exec import MeshSpec, Planner, build_apply
-    from repro.models.cnn import resnet, vgg
     mesh_spec = MeshSpec.parse(args.mesh) if args.mesh else None
     key = jax.random.PRNGKey(args.seed)
     shape = (ccfg.image, ccfg.image, ccfg.channels)
-    if ccfg.arch == "vgg16":
-        mods, params = vgg.init_vgg16(key, shape, ccfg.width_mult,
-                                      ccfg.n_classes)
-        head_apply = vgg.head_apply
-    else:
-        mods, params = resnet.init_resnet50(key, shape, ccfg.width_mult,
-                                            n_classes=ccfg.n_classes)
-        head_apply = resnet.head_apply
+    init, head_apply = CNN_TRUNKS[ccfg.arch]
+    options = {}
+    if args.layer_scale_init is not None:
+        if ccfg.arch != "convnext_t":
+            raise ValueError(f"--layer-scale-init: {ccfg.arch} has no layer "
+                             "scale")
+        options["layer_scale"] = args.layer_scale_init
+    mods, params = init(key, shape, ccfg.width_mult, n_classes=ccfg.n_classes,
+                        **options)
 
     # resolve the plan request: --budget-gb auto-selects engine+N via
     # Planner.for_budget; --strategy/--rows pin them; else the config's
@@ -397,6 +411,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--row-chunks", type=int, default=0)
+    ap.add_argument("--layer-scale-init", type=float, default=None,
+                    help="ConvNeXt: gamma's initial value (default the "
+                         "published 1e-6)")
     ap.add_argument("--strategy", default=None)
     ap.add_argument("--rows", type=int, default=None)
     ap.add_argument("--budget-gb", type=float, default=None,
@@ -438,7 +455,7 @@ def main(argv=None):
                         preset=args.preset)
     try:
         with profiled(args):
-            if args.arch in ("vgg16", "resnet50"):
+            if args.arch in CNN_TRUNKS:
                 train_cnn(args)
             else:
                 train_lm(args)

@@ -11,6 +11,11 @@ Every module implements the protocol the row engines (``repro.core.overlap``
       ``x`` covers global input rows ``iv_in``; returns exactly the rows
       ``out_iv`` of the global output, computed with semi-closed padding.
 
+ConvNeXt's modules (:class:`LayerNorm2d`, :class:`ConvNeXtBlock`) keep the
+same protocol: LayerNorm works on one pixel at a time, so rows are exact at
+the published normalisation.  :class:`Chain` runs a few primitives as one
+trunk module (ConvNeXt's stem and downsampling layers).
+
 Norm note (see DESIGN.md): BatchNorm here normalises with running
 statistics inside ``apply`` so that row-centric and column-centric
 execution are bit-identical; batch-moment *updates* are provided separately
@@ -22,12 +27,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Sequence, Tuple
+from typing import ClassVar, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core.convmath import (
     Geometry,
     IDENTITY,
@@ -49,7 +55,9 @@ def _he_init(key, shape, fan_in, dtype):
 @dataclasses.dataclass(frozen=True)
 class Conv:
     """2-D convolution, square kernel, symmetric W padding, semi-closed H
-    padding in row mode."""
+    padding in row mode.  ``groups`` splits the channels into groups
+    convolved apart (``groups == cin == cout`` is a depthwise conv); the
+    kernel is ``(k, k, cin // groups, cout)``."""
 
     cout: int
     k: int = 3
@@ -57,6 +65,7 @@ class Conv:
     p: int = 1
     bias: bool = True
     dtype: str = "float32"
+    groups: int = 1
 
     @property
     def geometry(self) -> Geometry:
@@ -66,8 +75,10 @@ class Conv:
         h, w, cin = in_shape
         dt = jnp.dtype(self.dtype)
         wkey, _ = jax.random.split(key)
-        fan_in = self.k * self.k * cin
-        params = {"w": _he_init(wkey, (self.k, self.k, cin, self.cout), fan_in, dt)}
+        cin_g = cin // self.groups
+        fan_in = self.k * self.k * cin_g
+        params = {"w": _he_init(wkey, (self.k, self.k, cin_g, self.cout),
+                                fan_in, dt)}
         if self.bias:
             params["b"] = jnp.zeros((self.cout,), dt)
         return params
@@ -87,6 +98,7 @@ class Conv:
             window_strides=(self.s, self.s),
             padding=(pad_h, (self.p, self.p)),
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            feature_group_count=self.groups,
         )
         if self.bias:
             y = y + params["b"]
@@ -194,6 +206,38 @@ class BatchNorm:
     def apply(self, params, x):
         inv = lax.rsqrt(params["var"] + self.eps) * params["scale"]
         return x * inv + (params["bias"] - params["mean"] * inv)
+
+    def apply_row(self, params, x, iv_in, h_in, out_iv):
+        off = out_iv[0] - iv_in[0]
+        y = self.apply(params, x)
+        return lax.slice_in_dim(y, off, off + (out_iv[1] - out_iv[0]), axis=1)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerNorm2d:
+    """LayerNorm over the channels of each pixel (channels-last), as
+    ConvNeXt normalises: mean and biased variance of one pixel's C values,
+    then a trained scale and bias per channel.  Row-exact: no pixel reads
+    another."""
+
+    eps: float = 1e-6
+
+    def init(self, key, in_shape):
+        c = in_shape[-1]
+        return {"scale": jnp.ones((c,), jnp.float32),
+                "bias": jnp.zeros((c,), jnp.float32)}
+
+    def out_shape(self, in_shape):
+        return in_shape
+
+    def in_interval(self, out_iv, h_in):
+        return out_iv
+
+    def apply(self, params, x):
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+        return ((x - mean) * lax.rsqrt(var + self.eps) * params["scale"]
+                + params["bias"])
 
     def apply_row(self, params, x, iv_in, h_in, out_iv):
         off = out_iv[0] - iv_in[0]
@@ -316,6 +360,113 @@ class Bottleneck:
             off = out_iv[0] - first
             r = lax.slice_in_dim(xs, off, off + (out_iv[1] - out_iv[0]), axis=1)
         return jnp.maximum(y + r, 0)
+
+
+# ---------------------------------------------------------------------------
+# Composites for ConvNeXt (Liu et al., CVPR 2022)
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Chain:
+    """A few modules run as one trunk module under one program scope
+    (``name``): ConvNeXt's stem (4x4/4 conv, LayerNorm) and its
+    downsampling layers (LayerNorm, 2x2/2 conv).  Params are the parts'
+    params, in order."""
+
+    name: str
+    parts: Tuple
+
+    def init(self, key, in_shape):
+        params, _ = init_trunk(self.parts, key, in_shape)
+        return params
+
+    def out_shape(self, in_shape):
+        for m in self.parts:
+            in_shape = m.out_shape(in_shape)
+        return in_shape
+
+    def in_interval(self, out_iv, h_in):
+        return trunk_in_intervals(self.parts, h_in, out_iv)[0]
+
+    def apply(self, params, x):
+        with obs.scope(self.name):
+            return apply_trunk(self.parts, params, x)
+
+    def apply_row(self, params, x, iv_in, h_in, out_iv):
+        ivs = trunk_in_intervals(self.parts, h_in, out_iv)
+        hs = trunk_heights(self.parts, h_in)
+        with obs.scope(self.name):
+            off = ivs[0][0] - iv_in[0]
+            y = lax.slice_in_dim(x, off, off + (ivs[0][1] - ivs[0][0]), axis=1)
+            for l, (m, p) in enumerate(zip(self.parts, params)):
+                y = m.apply_row(p, y, ivs[l], hs[l], ivs[l + 1])
+        return y
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvNeXtBlock:
+    """ConvNeXt block: 7x7 depthwise conv (pad 3) -> LayerNorm over the
+    channels -> 1x1 C->4C -> GELU (erf form) -> 1x1 4C->C -> layer scale
+    gamma, added to the block input.  One trunk module whose only spatial
+    op is the depthwise conv, so its row interval is that conv's.  The scopes
+    ``dwconv``, ``norm`` and ``pw_mlp`` (both 1x1 convs, the GELU and
+    gamma) name its ops in the compiled step."""
+
+    c: int
+    #: gamma's initial value, the published 1e-6
+    layer_scale: float = 1e-6
+    k: ClassVar[int] = 7
+    expansion: ClassVar[int] = 4
+
+    def _parts(self):
+        dw = Conv(self.c, k=self.k, s=1, p=self.k // 2, groups=self.c)
+        pw1 = Conv(self.expansion * self.c, k=1, s=1, p=0)
+        pw2 = Conv(self.c, k=1, s=1, p=0)
+        return dw, LayerNorm2d(), pw1, pw2
+
+    @property
+    def held_channels(self) -> int:
+        """Channels of the widest activation per output pixel (the
+        hidden expansion), which the planner prices in place of ``c``."""
+        return self.expansion * self.c
+
+    def init(self, key, in_shape):
+        dw, ln, pw1, pw2 = self._parts()
+        keys = jax.random.split(key, 4)
+        hidden = in_shape[:2] + (self.expansion * self.c,)
+        return {"dw": dw.init(keys[0], in_shape),
+                "norm": ln.init(keys[1], in_shape),
+                "pw1": pw1.init(keys[2], in_shape),
+                "pw2": pw2.init(keys[3], hidden),
+                "gamma": jnp.full((self.c,), self.layer_scale, jnp.float32)}
+
+    def out_shape(self, in_shape):
+        return in_shape
+
+    def in_interval(self, out_iv, h_in):
+        return self._parts()[0].in_interval(out_iv, h_in)
+
+    def _branch(self, params, y):
+        _, ln, pw1, pw2 = self._parts()
+        with obs.scope("norm"):
+            y = ln.apply(params["norm"], y)
+        with obs.scope("pw_mlp"):
+            y = jax.nn.gelu(pw1.apply(params["pw1"], y), approximate=False)
+            return pw2.apply(params["pw2"], y) * params["gamma"]
+
+    def apply(self, params, x):
+        with obs.scope("dwconv"):
+            y = self._parts()[0].apply(params["dw"], x)
+        return x + self._branch(params, y)
+
+    def apply_row(self, params, x, iv_in, h_in, out_iv):
+        with obs.scope("dwconv"):
+            y = self._parts()[0].apply_row(params["dw"], x, iv_in, h_in,
+                                           out_iv)
+        off = out_iv[0] - iv_in[0]
+        r = lax.slice_in_dim(x, off, off + (out_iv[1] - out_iv[0]), axis=1)
+        return r + self._branch(params, y)
 
 
 # ---------------------------------------------------------------------------

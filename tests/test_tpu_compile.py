@@ -114,3 +114,23 @@ def test_row_input_gradients_keep_their_window(one_chip):
     assert pads
     for pad in pads:
         assert max(int(p) for p in re.findall(r"-?\d+", pad)) <= 2, pad
+
+
+def test_depthwise_conv_forms_count_their_flops(one_chip):
+    """A 7x7 depthwise conv's forward, input gradient and kernel gradient
+    compile to three convolutions, the last with ``batch_group_count=C``
+    and an output batch of one; ``bench/hlo.py`` counts each at the
+    ``2 N H W k^2 C`` FLOPs it needs (XLA:CPU instead rewrites the kernel
+    gradient as a full C x C convolution, C times the work)."""
+    from bench import hlo
+
+    n, h, c, k = 2, 16, 8, 7
+    conv = Conv(c, k=k, p=k // 2, bias=False, groups=c)
+    text = _compiled_text(
+        jax.grad(lambda w, x: jnp.sum(conv.apply({"w": w}, x) ** 2),
+                 argnums=(0, 1)),
+        one_chip, ((k, k, 1, c), jnp.float32), ((n, h, h, c), jnp.float32))
+    assert re.search(r"convolution\(.*batch_group_count=8", text)
+    assert len(re.findall(r"convolution\(.*feature_group_count=8", text)) == 2
+    ops = hlo.conv_ops(text)
+    assert [op.flops for op in ops.values()] == [2 * n * h * h * k * k * c] * 3
